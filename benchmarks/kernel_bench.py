@@ -96,7 +96,7 @@ def _roofline(plan, B_a, G, K, N, quiet):
     q = jnp.asarray(rng.normal(size=(B, KV, rep, hd)), jnp.float32)
     lens = np.array([24, 70, 128, 9], np.int32)
     fd = lambda q_, kp_, vp_, bt_, l_: flash_decode(
-        q_, kp_, vp_, bt_, l_, n_splits=2, interpret=True)
+        q_, kp_, vp_, bt_, l_, n_splits=2)
     largs = (q, kp, vp, bt, jnp.asarray(lens))
     # the model floor counts only LIVE pages' K/V traffic — the block
     # table's decoupling of capacity from traffic is the claim

@@ -71,6 +71,9 @@ generation: int = 0
 _stats_lock = threading.Lock()
 _registry = None
 _tuned_keys: List[str] = []
+# candidates dropped by a sweep — raised, or disagreed with the oracle —
+# each named with its shape key and reason, never dropped in silence
+_failed: List[Dict[str, Any]] = []
 
 
 def registry():
@@ -87,6 +90,7 @@ def reset_stats() -> None:
     registry().reset()
     with _stats_lock:
         _tuned_keys.clear()
+        _failed.clear()
 
 
 def snapshot_stats() -> Dict[str, Any]:
@@ -96,7 +100,15 @@ def snapshot_stats() -> Dict[str, Any]:
     with _stats_lock:
         return {"lookup_hits": int(reg.get_counter("lookup_hits")),
                 "lookup_misses": int(reg.get_counter("lookup_misses")),
-                "tuned_keys": list(_tuned_keys)}
+                "tuned_keys": list(_tuned_keys),
+                "failed_candidates": [dict(f) for f in _failed]}
+
+
+def _candidate_failed(key: str, config: Dict[str, Any], reason: str) -> None:
+    registry().inc("candidate_failures")
+    with _stats_lock:
+        _failed.append({"key": key, "config": dict(config),
+                        "reason": reason})
 
 
 # ---------------------------------------------------------------------------
@@ -185,9 +197,12 @@ def shape_key(M: int, K: int, N: int, *, B_a: int, G: int, D_p: int,
 
 def candidates(M: int, K: int, N: int, *, B_a: int, G: int,
                include_pallas: Optional[bool] = None) -> List[Dict[str, Any]]:
-    """Candidate configs for a shape.  Pallas candidates only run where
-    they are compiled (TPU) — interpret mode timings are meaningless —
-    unless forced with ``REPRO_TLMAC_TUNE_PALLAS=1``.
+    """Candidate configs for a shape.  The Pallas lookup kernels
+    ('fused', 'pallas') join only when asked for (``include_pallas`` or
+    ``REPRO_TLMAC_TUNE_PALLAS=1``): off-TPU they run in interpret mode,
+    whose timings are meaningless, and the TPU compiler refuses them
+    (a ``(bm, 1, D_p)`` output block, then the in-kernel ``jnp.take``
+    table gather) until they are rebuilt.
 
     'pallas-onehot' is NOT a default candidate: its MXU-only addressing
     measures ~2 orders of magnitude slower than every other impl at
@@ -201,10 +216,7 @@ def candidates(M: int, K: int, N: int, *, B_a: int, G: int,
             cands.append({"impl": "xla", "chunk": chunk})
             cands.append({"impl": "xla-kscan", "chunk": chunk})
     if include_pallas is None:
-        include_pallas = (
-            jax.default_backend() == "tpu"
-            or os.environ.get("REPRO_TLMAC_TUNE_PALLAS") == "1"
-        )
+        include_pallas = os.environ.get("REPRO_TLMAC_TUNE_PALLAS") == "1"
     if include_pallas:
         include_onehot = os.environ.get("REPRO_TLMAC_TUNE_ONEHOT") == "1"
         for gather in ("take",) + (("onehot",) if include_onehot else ()):
@@ -300,8 +312,9 @@ def tune(
     verify: bool = True,
 ) -> Dict[str, Any]:
     """Time candidates on concrete operands; persist and return the
-    winner's config.  Candidates that fail (shape constraints) or are
-    not bit-exact are discarded."""
+    winner's config.  Candidates that raise (shape constraints, a
+    compiler refusal) or are not bit-exact are discarded and named in
+    ``snapshot_stats()['failed_candidates']``."""
     from repro.kernels import ops, ref as _ref
 
     M, K = a_codes.shape
@@ -333,9 +346,11 @@ def tune(
             ).block_until_ready()
         try:
             if want is not None and not np.array_equal(np.asarray(run()), want):
+                _candidate_failed(key, cand, "not bit-exact vs the oracle")
                 continue
             us = _time(run, reps)
-        except Exception:
+        except Exception as e:
+            _candidate_failed(key, cand, f"{type(e).__name__}: {e}"[:500])
             continue
         if cand == baseline_cfg:
             baseline_us = us
@@ -463,9 +478,11 @@ def tune_attention(
             if want is not None and not np.allclose(
                     np.asarray(run(), np.float32), want,
                     rtol=2e-2, atol=2e-2):
+                _candidate_failed(key, cand, "disagrees with the lax oracle")
                 continue
             us = _time(run, reps)
-        except Exception:
+        except Exception as e:
+            _candidate_failed(key, cand, f"{type(e).__name__}: {e}"[:500])
             continue
         runners[json.dumps(cand, sort_keys=True)] = run
         if cand == {"impl": ATTN_DEFAULT_IMPL}:

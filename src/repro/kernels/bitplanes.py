@@ -9,10 +9,13 @@ full-K rows so the strided group gather stays static.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.platform import resolve_interpret
 
 
 def _kernel(a_ref, out_ref, *, B_a: int, G: int):
@@ -30,7 +33,8 @@ def _kernel(a_ref, out_ref, *, B_a: int, G: int):
 
 @functools.partial(jax.jit, static_argnames=("B_a", "G", "bm", "interpret"))
 def pack_bitplanes_pallas(
-    a_codes: jnp.ndarray, *, B_a: int, G: int, bm: int = 256, interpret: bool = True
+    a_codes: jnp.ndarray, *, B_a: int, G: int, bm: int = 256,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     M, K = a_codes.shape
     assert K % G == 0
@@ -44,6 +48,6 @@ def pack_bitplanes_pallas(
         in_specs=[pl.BlockSpec((bm, K), lambda mi: (mi, 0))],
         out_specs=pl.BlockSpec((B_a, bm, K // G), lambda mi: (0, mi, 0)),
         out_shape=jax.ShapeDtypeStruct((B_a, Mp, K // G), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(a)
     return out[:, :M]

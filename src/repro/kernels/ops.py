@@ -12,7 +12,7 @@
                 plane.  Fastest when the [kg*2^G, N] expanded table fits
                 comfortably (small K or small N), pays full
                 materialisation otherwise.
-- 'pallas'    : the Pallas TPU kernel (interpret=True on CPU);
+- 'pallas'    : the Pallas TPU kernel (interpreted off-TPU);
                 gather='take'
 - 'pallas-onehot' : Pallas kernel with MXU-only addressing
 - 'fused'     : the fused revisit-hoisted Pallas megakernel
@@ -46,12 +46,15 @@ from repro.kernels.tlmac_fused import rowbase_from_plan, tlmac_matmul_fused
 from repro.kernels.tlmac_gemm import tlmac_gemm
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 # resolved-'auto'-config memo, invalidated by autotune.generation bumps
 _AUTO_MEMO: dict = {}
+
+
+def auto_resolutions() -> list:
+    """What ``impl='auto'`` resolved to in this process, one entry per
+    lookup-GEMM shape: ``{"M", "K", "N", "config"}``."""
+    return [{"M": k[0], "K": k[1], "N": k[2], "config": dict(v[1])}
+            for k, v in _AUTO_MEMO.items()]
 
 
 def dense_int_matmul(a_codes: jnp.ndarray, w_codes: jnp.ndarray) -> jnp.ndarray:
@@ -331,7 +334,7 @@ def dispatch_config(
             a_codes, table, exec_idx, step_cluster,
             B_a=B_a, G=G, N=N,
             bm=config.get("bm", 128), bk=config.get("bk", 128),
-            gather=config.get("gather", "take"), interpret=_interpret(),
+            gather=config.get("gather", "take"),
         )
     if impl in ("pallas", "pallas-onehot"):
         M, K = a_codes.shape
@@ -345,7 +348,6 @@ def dispatch_config(
             B_a=B_a, G=G, N=N,
             bm=config.get("bm", 128), bk=config.get("bk", 128),
             gather="take" if impl == "pallas" else "onehot",
-            interpret=_interpret(),
         )
     raise ValueError(f"unknown impl {impl!r}")
 

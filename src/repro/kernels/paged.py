@@ -32,8 +32,8 @@ path pays is gone.
                  to the longest live slot's block) — per-token work is
                  O(context), not O(S_max).  The production CPU path.
 - ``flash``      the Pallas split-K kernel (kernels/flash_decode.py):
-                 GQA head-packing, per-(slot, kv-head, split) grid,
-                 block table via scalar prefetch.  TPU hot path.
+                 one whole page (every KV head) per grid step, per-
+                 (slot, split) grid, block table via scalar prefetch.
 - ``auto``       shape-keyed autotune (kernels/autotune.py): candidates
                  are verified against the ``lax`` oracle, then timed;
                  trace-time lookups are pure host-side cache reads and
@@ -60,8 +60,9 @@ Quantisation happens on write (post-rotary K, raw V), dequantisation
 inside each attention reader: the lax oracle dequantises its gather,
 ``flash-lax`` dequantises per visited page inside the online-softmax
 loop, and the Pallas kernel loads code pages + their scale blocks
-through the same block-table indexing and dequantises in-register
-(int4 unpacks with shifts).  KV read/write traffic and pool bytes drop
+through the same block-table indexing and folds the scales into its
+scores and probabilities (int4 contracts each nibble with its own
+half of the query).  KV read/write traffic and pool bytes drop
 ~2x (int8) / ~4x (int4) relative to bf16; the scale sidecar costs
 ``2 / head_dim`` bytes per element (bf16 scales).
 """
@@ -194,12 +195,17 @@ def pack_int4(codes):
     return ((lo & 0x0F) | (hi << 4)).astype(jnp.int8)
 
 
+def int4_nibbles(packed):
+    """Sign-extended ``(low, high)`` nibbles of int8 ``[..., w]`` as
+    int32 ``[..., w]``: the even and odd elements ``pack_int4`` packed."""
+    p = packed.astype(jnp.int32)
+    return (p << 28) >> 28, (p << 24) >> 28
+
+
 def unpack_int4(packed):
     """Inverse of ``pack_int4``: int8 ``[..., w]`` -> ``[..., 2w]``
     sign-extended codes.  Lossless for codes in [-8, 7]."""
-    p = packed.astype(jnp.int32)
-    lo = (p << 28) >> 28
-    hi = (p << 24) >> 28
+    lo, hi = int4_nibbles(packed)
     out = jnp.stack([lo, hi], axis=-1)
     return out.reshape(*packed.shape[:-1], 2 * packed.shape[-1]).astype(
         jnp.int8)
@@ -571,8 +577,6 @@ def dispatch_attention(config, q, k_pages, v_pages, block_table, positions,
         B, Sq, H, hd = q.shape
         KV = k_pages.shape[2]
         rep = H // KV
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
         out = flash_decode(
             q.reshape(B, KV, rep, hd), k_pages, v_pages, block_table,
             positions + 1, window=window,

@@ -28,11 +28,14 @@ validated bit-exactly against the dense integer GEMM in interpret mode.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+
+from repro.kernels.platform import resolve_interpret
 
 
 def cluster_schedule(plan, bk: int = 8):
@@ -109,7 +112,7 @@ def tlmac_gemm_clustered(
     G: int,
     bm: int = 128,
     bk: int = 8,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """One-output-tile clustered lookup GEMM -> int32 [M, D_p]."""
     n_clus, ms, D_p = idx_sorted.shape
@@ -142,13 +145,9 @@ def tlmac_gemm_clustered(
         ],
         out_specs=pl.BlockSpec((bm, D_p), lambda mi, c, ki: (mi, 0)),
         out_shape=jax.ShapeDtypeStruct((Mp, D_p), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(codes_sorted, idx_sorted, table_pad)
     return out[:M]
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def run_clustered(plan, a_codes, B_a: int, bk: int = 8, bm: int = 128):
@@ -168,7 +167,7 @@ def run_clustered(plan, a_codes, B_a: int, bk: int = 8, bm: int = 128):
         codes_sorted.astype(jnp.int32),
         jnp.asarray(sched["idx_sorted"]),
         jnp.asarray(sched["table_pad"]),
-        B_a=B_a, G=G, bm=bm, bk=bk, interpret=_interpret(),
+        B_a=B_a, G=G, bm=bm, bk=bk,
     )
     return out
 
@@ -262,7 +261,7 @@ def tlmac_gemm_clustered_multi(
     G: int,
     bm: int = 128,
     bk: int = 8,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Whole-layer clustered lookup GEMM -> int32 [M, n_tiles*D_p].
 
@@ -303,7 +302,7 @@ def tlmac_gemm_clustered_multi(
         ],
         out_specs=pl.BlockSpec((bm, 1, D_p), lambda nt, mi, c, ki: (mi, nt, 0)),
         out_shape=jax.ShapeDtypeStruct((Mp, n_tiles, D_p), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(codes_sorted, idx_sorted, table_pad)
     return out.reshape(Mp, n_tiles * D_p)[:M]
 
@@ -330,5 +329,5 @@ def run_clustered_multi(plan, a_codes, B_a: int, N: int, bk: int = 8,
         codes_sorted.astype(jnp.int32),
         jnp.asarray(sched["idx_sorted"]),
         jnp.asarray(sched["table_pad"]),
-        B_a=B_a, G=G, bm=bm, bk=bk, interpret=_interpret(),
+        B_a=B_a, G=G, bm=bm, bk=bk,
     )

@@ -38,17 +38,14 @@ row and contribute nothing).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed TPUCompilerParams -> CompilerParams across jax versions; the
-# hints are an optimisation, so degrade to "no params" if neither exists
-_CompilerParams = getattr(
-    pltpu, "TPUCompilerParams", getattr(pltpu, "CompilerParams", None)
-)
+from repro.kernels.platform import resolve_interpret
 
 # staging budget for the hoisted rhs scratch [nk, bk*C, dp] f32; above
 # this the kernel recomputes rhs per visit instead (correct, just slower)
@@ -161,7 +158,7 @@ def tlmac_gemm_fused(
     bm: int = 128,
     bk: int = 128,
     gather: str = "take",
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     hoist_vmem_bytes: int = HOIST_VMEM_BYTES,
 ) -> jnp.ndarray:
     """Fused pack+lookup GEMM. Returns int32 [M, N]."""
@@ -191,11 +188,6 @@ def tlmac_gemm_fused(
     nk = KGp // bk
     hoist = nk * bk * C * D_p * 4 <= hoist_vmem_bytes
     grid = (n_tiles, Mp // bm, nk)
-    extra = {}
-    if _CompilerParams is not None:
-        extra["compiler_params"] = _CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")
-        )
     out = pl.pallas_call(
         functools.partial(
             _kernel, B_a=B_a, G=G, C=C, gather=gather, hoist=hoist
@@ -211,8 +203,10 @@ def tlmac_gemm_fused(
         scratch_shapes=[
             pltpu.VMEM((nk if hoist else 1, bk * C, D_p), jnp.float32)
         ],
-        interpret=interpret,
-        **extra,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")
+        ),
+        interpret=resolve_interpret(interpret),
     )(a, rowbase, table2d)
     return out.reshape(Mp, N)[:M]
 
@@ -229,7 +223,7 @@ def tlmac_matmul_fused(
     bm: int = 128,
     bk: int = 128,
     gather: str = "take",
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     hoist_vmem_bytes: int = HOIST_VMEM_BYTES,
 ) -> jnp.ndarray:
     """Plan-level wrapper: build rowbase, run the fused megakernel."""

@@ -36,10 +36,13 @@ the MXU contraction dims are multiples of 128 where possible.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.platform import resolve_interpret
 
 
 def _kernel(
@@ -107,7 +110,7 @@ def tlmac_gemm(
     bm: int = 128,
     bk: int = 128,
     gather: str = "take",
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Blocked Pallas lookup GEMM. Returns int32 [M, N]."""
     _, M, KG = codes.shape
@@ -143,6 +146,6 @@ def tlmac_gemm(
         ],
         out_specs=pl.BlockSpec((bm, 1, D_p), lambda nt, mi, ki: (mi, nt, 0)),
         out_shape=jax.ShapeDtypeStruct((Mp, n_tiles, D_p), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(codes, rowbase, table2d)
     return out.reshape(Mp, N)[:M]

@@ -35,7 +35,7 @@ from repro.configs import SHAPES, get_config, list_archs
 from repro.data.pipeline import batch_specs
 from repro.launch import analytic
 from repro.launch.hlo_analysis import Roofline, parse_collectives
-from repro.launch.mesh import make_production_mesh, mesh_context
+from repro.launch.mesh import make_production_mesh
 from repro.models import lm
 from repro.optim.adamw import AdamWConfig, adamw_init
 from repro.parallel.sharding import filter_specs, make_shardings
@@ -188,7 +188,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, skip_hlo=False):
     n_chips = int(np.prod(mesh.devices.shape))
     t0 = time.time()
     try:
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             if shape.kind == "train":
                 lowered, mult = _lower_train(cfg, shape, mesh)
             elif shape.kind == "prefill":

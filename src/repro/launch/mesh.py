@@ -17,34 +17,19 @@ from __future__ import annotations
 import jax
 
 
-def make_mesh_compat(shape, axes):
-    """jax.make_mesh across jax versions: ``axis_types`` (and
-    ``jax.sharding.AxisType``) only exist from jax 0.5; all axes here
-    are Auto, which is also the old default — so just drop the kwarg
-    when the installed jax predates it."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis Auto: the model code places
+    data with sharding hints and lets GSPMD propagate the rest."""
     return jax.make_mesh(
-        shape, axes, axis_types=(axis_type.Auto,) * len(axes)
-    )
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh():
     """1-device mesh for CPU smoke runs (same axis names, all size 1)."""
-    return make_mesh_compat((1, 1), ("data", "model"))
-
-
-def mesh_context(mesh):
-    """``jax.set_mesh(mesh)`` where available (jax >= 0.5); older jax
-    activates a mesh by using it directly as a context manager."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
+    return make_mesh((1, 1), ("data", "model"))

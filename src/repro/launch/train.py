@@ -20,12 +20,14 @@ import numpy as np
 
 from repro.configs import SHAPES, get_config, smoke_config
 from repro.data.pipeline import SyntheticLMData
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim.adamw import AdamWConfig
 from repro.train.ft import FaultTolerantRunner, PreemptionSchedule
 from repro.train.trainer import TrainConfig, TrainLoop
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")

@@ -19,7 +19,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def ring_ag_matmul(x, w, mesh: Mesh, axis: str = "model"):
@@ -56,11 +55,11 @@ def ring_ag_matmul(x, w, mesh: Mesh, axis: str = "model"):
         _, out = jax.lax.fori_loop(0, n, step, (x_blk.astype(jnp.float32), out0))
         return out
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis, None), P(None, None)),
         out_specs=P(None, None),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(x, w)
 
@@ -103,10 +102,10 @@ def ring_rs_matmul(x, w, mesh: Mesh, axis: str = "model"):
         acc0 = jnp.zeros((m_shard, w_blk.shape[1]), jnp.float32)
         return jax.lax.fori_loop(0, n, step, acc0)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, None), P(axis, None)),
         out_specs=P(axis, None),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(x, w)

@@ -14,7 +14,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def pipeline_apply(stage_fn, params_stacked, x_microbatches, mesh: Mesh,
@@ -73,10 +72,10 @@ def pipeline_apply(stage_fn, params_stacked, x_microbatches, mesh: Mesh,
         )
         return outs
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis), P(None)),
         out_specs=P(None),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(params_stacked, x_microbatches)

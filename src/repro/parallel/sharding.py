@@ -14,17 +14,9 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 
 def _active_axes():
-    get_am = getattr(jax.sharding, "get_abstract_mesh", None)
-    m = get_am() if get_am is not None else None
-    if not (hasattr(m, "empty") and not m.empty):
-        # jax < 0.5 (no jax.sharding.get_abstract_mesh, or nothing set):
-        # fall back to the thread-local physical mesh (Mesh context mgr)
-        try:
-            from jax._src.mesh import thread_resources
-            m = thread_resources.env.physical_mesh
-        except ImportError:
-            return None
-    if m is None or not hasattr(m, "empty") or m.empty:
+    """Axis names of the mesh set by ``jax.set_mesh``, or None."""
+    m = jax.sharding.get_abstract_mesh()
+    if m.empty:
         return None
     return set(m.axis_names)
 

@@ -97,6 +97,14 @@ class ServeLoop:
     def submit(self, req: Request):
         self.queue.append(req)
 
+    def prompt_logits(self, prompt) -> np.ndarray:
+        """f32 ``[vocab]`` logits at the last position of ``prompt``:
+        the prefill a solo batch of this prompt runs."""
+        tokens = jnp.asarray(np.asarray(prompt, np.int32)[None])
+        logits, _ = lm.prefill(self.params, {"tokens": tokens}, self.cfg,
+                               S_max=self.S_max)
+        return np.asarray(logits[0], np.float32)
+
     def run(self):
         """Process the queue; greedy decoding. Returns finished requests."""
         while self.queue:
@@ -114,8 +122,8 @@ class ServeLoop:
     def _write_slot(self, caches, caches_one, i: int):
         """Copy a 1-request prefill cache into batch slot i (axis 1 of
         every [n_layers, B, ...] leaf).  Jitted with the batch caches
-        donated (off-CPU): the update then aliases the existing buffers
-        instead of copying the full multi-GB cache once per refill."""
+        donated: the update then aliases the existing buffers instead
+        of copying the full multi-GB cache once per refill."""
         if self._write_jit is None:
             def write(cb, co, idx):
                 def upd(c, c1):
@@ -125,8 +133,7 @@ class ServeLoop:
                 return [
                     jax.tree.map(upd, b, o) for b, o in zip(cb, co)
                 ]
-            donate = () if jax.default_backend() == "cpu" else (0,)
-            self._write_jit = jax.jit(write, donate_argnums=donate)
+            self._write_jit = jax.jit(write, donate_argnums=(0,))
         return self._write_jit(caches, caches_one, jnp.int32(i))
 
     def _try_refill(self, caches, cur_np, L: int, slot_i: int):

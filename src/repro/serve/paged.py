@@ -454,29 +454,30 @@ class PagedServeLoop:
         # prefill chunk, one decode step, and — speculation enabled —
         # one verify window.  (The CoW page copy below is a
         # cache-to-cache device memcpy, not a forward pass; it adds
-        # exactly one more trace of its own.)
-        donate = () if jax.default_backend() == "cpu" else (1,)
+        # exactly one more trace of its own.)  Every cache-writing jit
+        # donates the cache on every backend, so the pool is updated in
+        # place and a stale reference to it fails loudly wherever the
+        # tests run, not first on the chip.
         self._prefill_chunk = jax.jit(
             lambda p, c, t, start, bt_row, last: lm.prefill_chunk(
                 p, c, t, start, bt_row, cfg, last=last),
-            donate_argnums=donate,
+            donate_argnums=(1,),
         )
         self._decode = jax.jit(
             lambda p, c, t, pos, bt: lm.decode_step_paged(
                 p, c, t, pos, bt, cfg),
-            donate_argnums=donate,
+            donate_argnums=(1,),
         )
         self._verify = jax.jit(
             lambda p, c, t, pos, nw, bt: lm.verify_step_paged(
                 p, c, t, pos, nw, bt, cfg),
-            donate_argnums=donate,
+            donate_argnums=(1,),
         ) if self.drafter is not None else None
-        cow_donate = () if jax.default_backend() == "cpu" else (0,)
         # a fresh lambda per loop keeps the jit cache (and its
         # _cache_size trace count) per-instance, like the two above
         self._copy_page = jax.jit(
             lambda c, src, dst: lm.cache_copy_page(c, src, dst),
-            donate_argnums=cow_donate)
+            donate_argnums=(0,))
         # swap gather/scatter: fixed ring-width page moves, so exactly
         # one trace each for the loop's lifetime (asserted in
         # check_compiled; compiled_shapes() stays the three forward
@@ -487,7 +488,7 @@ class PagedServeLoop:
                 lambda c, pids: lm.cache_swap_out(c, pids))
             self._swap_scatter = jax.jit(
                 lambda c, s, pids: lm.cache_swap_in(c, s, pids),
-                donate_argnums=cow_donate)
+                donate_argnums=(0,))
         else:
             self._swap_gather = None
             self._swap_scatter = None
@@ -687,6 +688,32 @@ class PagedServeLoop:
         self.tel.observe("phase.cow_s", t1 - t0)
         self.cow_copies += 1
 
+    def _run_prefill_chunks(self, tokens, row, ci0: int, rid):
+        """Prefill ``tokens`` from chunk ``ci0`` on through the slot
+        whose block-table row is ``row``: every chunk padded to the one
+        compiled ``[1, chunk]`` shape.  Returns the last chunk's logits
+        at the last token (None when no chunk runs)."""
+        tel, C, L = self.tel, self.chunk, len(tokens)
+        bt_row = jnp.asarray(row)
+        n_chunks = -(-L // C)
+        logits = None
+        for ci in range(ci0, n_chunks):
+            buf = np.zeros(C, np.int32)
+            seg = tokens[ci * C:(ci + 1) * C]
+            buf[: len(seg)] = seg
+            last = (L - 1) - ci * C if ci == n_chunks - 1 else 0
+            t0c = tel.now()
+            with tel.annotate("repro.serve.prefill_chunk"):
+                logits, self.caches = self._prefill_chunk(
+                    self.params, self.caches, jnp.asarray(buf[None]),
+                    jnp.int32(ci * C), bt_row, jnp.int32(last),
+                )
+            t1c = tel.now()
+            tel.event("prefill_chunk", rid, t0=t0c, t1=t1c,
+                      chunk=ci, start=ci * C, tokens=C)
+            tel.observe("phase.prefill_chunk_s", t1c - t0c)
+        return logits
+
     def _admit(self, slot_i: int) -> str:
         """Prefill the scheduler's best entry into a free slot.
         Returns 'admitted' (live slot installed), 'finished' (the
@@ -808,27 +835,11 @@ class PagedServeLoop:
         row = np.zeros(self.spec.max_blocks, np.int32)
         row[:total] = blocks
         self.block_table[slot_i] = row
-        bt_row = jnp.asarray(row)
         n_chunks = -(-L // C)
-        logits = None
         # perf_counter, not tel.now(): the NULL facade's clock returns
         # 0.0, and the swap policy needs real rates with telemetry off
         t0p = time.perf_counter() if self.swap_policy is not None else 0.0
-        for ci in range(ci0, n_chunks):
-            buf = np.zeros(C, np.int32)
-            seg = tokens[ci * C:(ci + 1) * C]
-            buf[: len(seg)] = seg
-            last = (L - 1) - ci * C if ci == n_chunks - 1 else 0
-            t0c = tel.now()
-            with tel.annotate("repro.serve.prefill_chunk"):
-                logits, self.caches = self._prefill_chunk(
-                    self.params, self.caches, jnp.asarray(buf[None]),
-                    jnp.int32(ci * C), bt_row, jnp.int32(last),
-                )
-            t1c = tel.now()
-            tel.event("prefill_chunk", rid, t0=t0c, t1=t1c,
-                      chunk=ci, start=ci * C, tokens=C)
-            tel.observe("phase.prefill_chunk_s", t1c - t0c)
+        logits = self._run_prefill_chunks(tokens, row, ci0, rid)
         run_tokens = (n_chunks - ci0) * C
         self.prefill_tokens_run += run_tokens
         self.prefill_tokens_saved += ci0 * C
@@ -1532,6 +1543,42 @@ class PagedServeLoop:
             self.spec_accepted += min(appended, m)
             freed |= fin
         return freed
+
+    def prompt_logits(self, prompt, continuation=()) -> np.ndarray:
+        """f32 ``[vocab]`` logits at the last position of ``prompt`` +
+        ``continuation``: ``prompt`` through ``_admit``'s chunk prefill,
+        then each ``continuation`` token through one decode step — the
+        serve loop's own compiled forwards — over fresh private pages
+        that are freed again; nothing is admitted.  Slot rows other than
+        the scored one are idle (scratch page).  With an empty
+        ``continuation`` these are the logits ``_admit`` argmaxes —
+        what a reference check compares."""
+        tokens = np.asarray(prompt, np.int32)
+        cont = [int(t) for t in continuation]
+        L = len(tokens)
+        if not (0 < L and L + len(cont) <= self.S_max):
+            raise ValueError(f"{L} + {len(cont)} tokens outside "
+                             f"(0, {self.S_max}]")
+        blocks = self._alloc_with_evict(self._worst_blocks(L, len(cont) + 1))
+        if blocks is None:
+            raise PoolExhaustedError("no free pages to score a prompt")
+        try:
+            row = np.zeros(self.spec.max_blocks, np.int32)
+            row[:len(blocks)] = blocks
+            logits = self._run_prefill_chunks(tokens, row, 0, None)
+            bt = np.zeros_like(self.block_table)
+            bt[0] = row
+            for n, t in enumerate(cont):
+                cur = np.zeros((self.B, 1), np.int32)
+                pos = np.zeros(self.B, np.int32)
+                cur[0, 0], pos[0] = t, L + n
+                logits, self.caches = self._decode(
+                    self.params, self.caches, jnp.asarray(cur),
+                    jnp.asarray(pos), jnp.asarray(bt))
+                logits = logits[0]
+            return np.asarray(logits, np.float32)
+        finally:
+            self.pages.release(blocks)
 
     # -- introspection -------------------------------------------------------
 

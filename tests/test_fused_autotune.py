@@ -168,6 +168,40 @@ def test_autotune_rejects_non_bitexact(monkeypatch, tmp_path):
         autotune.reset_cache()
 
 
+def test_autotune_names_every_failed_candidate(monkeypatch, tmp_path):
+    """A candidate that raises (a compiler refusal, a shape it cannot
+    take) or disagrees with the oracle is dropped, counted and named —
+    never swallowed in silence."""
+    monkeypatch.setenv(autotune.CACHE_ENV, str(tmp_path / "at.json"))
+    autotune.reset_cache()
+    autotune.reset_stats()
+    try:
+        a, w, t, e, c, _ = _setup(19, 24, 64, 4, 2, 2, 3)
+        real = ops.dispatch_config
+
+        def faulty(config, *args, **kw):
+            if config["impl"] == "xla-flat":
+                raise ValueError("refused by the compiler")
+            out = real(config, *args, **kw)
+            return out + 1 if config["impl"] == "ref" else out
+
+        monkeypatch.setattr(ops, "dispatch_config", faulty)
+        cfg = autotune.tune(a, t, e, c, B_a=2, G=3, N=64, reps=2,
+                            cands=[{"impl": "xla-flat"}, {"impl": "ref"}])
+        assert cfg == {"impl": "xla"}
+        failed = autotune.snapshot_stats()["failed_candidates"]
+        assert [f["config"] for f in failed] == [{"impl": "xla-flat"},
+                                                 {"impl": "ref"}]
+        assert "refused by the compiler" in failed[0]["reason"]
+        assert "bit-exact" in failed[1]["reason"]
+        assert autotune.registry().get_counter("candidate_failures") == 2
+        autotune.reset_stats()
+        assert autotune.snapshot_stats()["failed_candidates"] == []
+    finally:
+        autotune.reset_cache()
+        autotune.reset_stats()
+
+
 # ---------------------------------------------------------------------------
 # multi-output-tile clustered kernel
 # ---------------------------------------------------------------------------

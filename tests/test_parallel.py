@@ -30,8 +30,8 @@ def test_ring_ag_matmul_matches_dense():
     print(_run("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.parallel.overlap import ring_ag_matmul
-        from repro.launch.mesh import make_mesh_compat
-        mesh = make_mesh_compat((4,), ("model",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4,), ("model",))
         M, K, N = 32, 16, 24
         x = jax.random.normal(jax.random.PRNGKey(0), (M, K))
         w = jax.random.normal(jax.random.PRNGKey(1), (K, N))
@@ -51,8 +51,8 @@ def test_ring_rs_matmul_matches_dense():
     print(_run("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.parallel.overlap import ring_rs_matmul
-        from repro.launch.mesh import make_mesh_compat
-        mesh = make_mesh_compat((4,), ("model",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4,), ("model",))
         M, K, N = 32, 16, 24
         x = jax.random.normal(jax.random.PRNGKey(0), (M, K))
         w = jax.random.normal(jax.random.PRNGKey(1), (K, N))
@@ -68,8 +68,8 @@ def test_pipeline_matches_sequential():
     print(_run("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.parallel.pipeline import pipeline_apply
-        from repro.launch.mesh import make_mesh_compat
-        mesh = make_mesh_compat((4,), ("pod",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4,), ("pod",))
         S, M, mb, d = 4, 6, 8, 16
         params = jax.random.normal(jax.random.PRNGKey(0), (S, d, d)) * 0.3
 

@@ -184,8 +184,8 @@ def test_elastic_restore_resharding(tmp_path):
 
     tree = {"w": jnp.arange(32.0).reshape(4, 8)}
     save_checkpoint(str(tmp_path), 1, tree)
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1, 1), ("data", "model"))
     shardings = {"w": NamedSharding(mesh, P(None, "model"))}
     restored, _ = restore_checkpoint(str(tmp_path), tree, shardings=shardings)
     np.testing.assert_array_equal(np.asarray(restored["w"]),
