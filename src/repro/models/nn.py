@@ -314,8 +314,12 @@ def _serve_auto_allow():
             else _SERVE_AUTO_ALLOW_SHARDED)
 
 
+@jax.named_scope("repro.lookup_gemm")
 def _tlmac_gemm(params, aq, codes_fn, lead, cfg, fused: bool):
-    """One lookup GEMM from pre-quantised/packed activations."""
+    """One lookup GEMM from pre-quantised/packed activations.  The
+    scope holds the bit-plane pack, the GEMM and the dequant scale on
+    both paths and under every impl, so a trace reads the lookup GEMM's
+    device time by one name whatever implements it."""
     B_a, G = cfg.quant.a_bits, cfg.tlmac_G
     n_tiles, kg, dp = params["exec_idx"].shape
     N = n_tiles * dp

@@ -78,6 +78,11 @@ class Request:
                                   # DeadlineExceededError
                                   # (serve/scheduler.py); None on
                                   # success
+    queue_wait_s: Optional[float] = None  # paged loop: seconds from
+                                  # enqueue to the first admission (the
+                                  # scheduler's queue-wait observation);
+                                  # a resume keeps it.  None until
+                                  # admitted; the dense loop leaves it.
 
 
 class ServeLoop:

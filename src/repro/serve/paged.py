@@ -124,7 +124,7 @@ from repro.serve.scheduler import (AdmissionError, CancelledError,
                                    tenant_of)
 from repro.serve.spec import make_drafter
 from repro.serve.swap import StagingRing, SwapStore
-from repro.serve.telemetry import NULL, Histogram, Telemetry
+from repro.serve.telemetry import NULL, Histogram, Telemetry, annotate
 
 
 class PageManager:
@@ -337,10 +337,11 @@ class PagedServeLoop:
             getattr(cfg, "serve_check_invariants", False)
             if check_invariants is None else check_invariants)
         # unified observability (serve/telemetry.py): lifecycle tracer +
-        # metrics registry + jax.profiler annotations when enabled; the
-        # shared NULL no-op facade otherwise, so every instrumentation
-        # site below costs one attribute lookup and a pass when off.
-        # Purely host-side either way — the compile set is unaffected.
+        # metrics registry when enabled; the shared NULL no-op facade
+        # otherwise, so every instrumentation site below costs one
+        # attribute lookup and a pass when off.  The jax.profiler spans
+        # (telemetry.annotate) are on either way.  Purely host-side —
+        # the compile set is unaffected.
         tel_on = bool(getattr(cfg, "serve_telemetry", False)
                       if telemetry is None else telemetry)
         self.tel = Telemetry() if tel_on else NULL
@@ -680,12 +681,11 @@ class PagedServeLoop:
         """Copy-on-write: duplicate physical page ``src`` into the
         freshly-allocated ``dst`` across every layer's K/V pool."""
         t0 = self.tel.now()
-        with self.tel.annotate("repro.serve.cow_copy"):
+        with annotate("repro.serve.cow_copy"):
             self.caches = self._copy_page(self.caches, jnp.int32(src),
                                           jnp.int32(dst))
-        t1 = self.tel.now()
-        self.tel.event("cow_copy", t0=t0, t1=t1, src=src, dst=dst)
-        self.tel.observe("phase.cow_s", t1 - t0)
+        self.tel.event("cow_copy", t0=t0, t1=self.tel.now(), src=src,
+                       dst=dst)
         self.cow_copies += 1
 
     def _run_prefill_chunks(self, tokens, row, ci0: int, rid):
@@ -703,15 +703,13 @@ class PagedServeLoop:
             buf[: len(seg)] = seg
             last = (L - 1) - ci * C if ci == n_chunks - 1 else 0
             t0c = tel.now()
-            with tel.annotate("repro.serve.prefill_chunk"):
+            with annotate("repro.serve.prefill_chunk"):
                 logits, self.caches = self._prefill_chunk(
                     self.params, self.caches, jnp.asarray(buf[None]),
                     jnp.int32(ci * C), bt_row, jnp.int32(last),
                 )
-            t1c = tel.now()
-            tel.event("prefill_chunk", rid, t0=t0c, t1=t1c,
+            tel.event("prefill_chunk", rid, t0=t0c, t1=tel.now(),
                       chunk=ci, start=ci * C, tokens=C)
-            tel.observe("phase.prefill_chunk_s", t1c - t0c)
         return logits
 
     def _admit(self, slot_i: int) -> str:
@@ -733,6 +731,14 @@ class PagedServeLoop:
             # injected transient contention: the head waits one round
             self._injected_block = True
             return "blocked"
+        with annotate("repro.serve.admit"):
+            return self._admit_entry(slot_i, ent)
+
+    def _admit_entry(self, slot_i: int, ent: SchedEntry) -> str:
+        """``_admit`` for the head entry ``ent``: match, plan, alloc
+        (or 'blocked'), then pop, CoW, block-table row, the prefill
+        chunks and the first token's sync."""
+        t_start = self.tel.now()
         tokens = ent.tokens
         L = len(tokens)
         # record=False: a blocked head re-matches every refill round;
@@ -780,7 +786,9 @@ class PagedServeLoop:
             page_ids = self._alloc_with_evict(need)
         if page_ids is None:
             return "blocked"              # pool exhausted: request waits
-        self.sched.pop(ent)
+        wait = self.sched.pop(ent)
+        if ent.req.queue_wait_s is None:
+            ent.req.queue_wait_s = wait   # first admission only
         # the entry is live again: any host-store pages it parked are
         # plain shareable cache from here on (LRU-governed), no longer
         # owned by a waiting request — cancel purges apply only while
@@ -848,7 +856,9 @@ class PagedServeLoop:
             # real cost (the SLO bench's recompute-overhead number)
             self.resumes += 1
             self.resume_prefill_tokens += run_tokens
-        tok0 = int(np.asarray(jnp.argmax(logits)))
+        with annotate("repro.serve.sync"):
+            tok0 = int(np.asarray(jnp.argmax(logits)))
+        tel.observe("phase.admit_s", tel.now() - t_start)
         if self.swap_policy is not None and n_chunks > ci0:
             # the argmax force above synchronised the device, so the
             # window covers dispatch + execution of every live chunk
@@ -1131,7 +1141,7 @@ class PagedServeLoop:
             tail = [int(b) for b in blocks[base: base + R]]
             pids = np.zeros(R, np.int32)     # scratch-page padding
             pids[: len(tail)] = tail
-            with self.tel.annotate("repro.serve.swap_gather"):
+            with annotate("repro.serve.swap_gather"):
                 dev = self._swap_gather(self.caches, jnp.asarray(pids))
             for meta, host in ring.stage((base, len(tail)), dev):
                 stored += self._store_staged(full, meta, host, tenant)
@@ -1181,7 +1191,7 @@ class PagedServeLoop:
             padded = list(tail) + [tail[-1]] * (R - len(tail))
             staged = jax.tree.map(lambda *xs: np.stack(xs, axis=1),
                                   *[p.data for p in padded])
-            with self.tel.annotate("repro.serve.swap_scatter"):
+            with annotate("repro.serve.swap_scatter"):
                 self.caches = self._swap_scatter(
                     self.caches, jax.tree.map(jnp.asarray, staged),
                     jnp.asarray(pids))
@@ -1189,7 +1199,8 @@ class PagedServeLoop:
         # force the scatters so the observed copy rate is real (the
         # data dependency alone would already order them before the
         # first forward that reads the restored pages)
-        jax.block_until_ready(self.caches)
+        with annotate("repro.serve.sync"):
+            jax.block_until_ready(self.caches)
         self.swap_policy.observe_copy(nbytes, time.perf_counter() - t0)
         self.swapped_in_pages += len(host_pages)
         self.swap_in_bytes += nbytes
@@ -1226,8 +1237,17 @@ class PagedServeLoop:
         """One scheduling round: admissions into free slots, then at
         most one decode/verify forward over the live slots (preempting
         victims first if on-demand growth exhausts the pool), then
-        refill.  Returns True while work remains — an arrival-process
-        driver submits between steps; ``run`` just drains."""
+        refill.  Returns True while work remains — a caller feeding
+        arrivals submits between steps; ``run`` just drains.
+
+        The round runs inside the ``repro.serve.step`` profiler span,
+        which carries ``time.monotonic()`` at its start: the one
+        reading per step that maps the program's monotonic stamps onto
+        a capture's clock (serve/telemetry.py)."""
+        with annotate("repro.serve.step", monotonic_s=time.monotonic()):
+            return self._step()
+
+    def _step(self) -> bool:
         self.sched.tick()
         self._injected_block = False
         if self.faults.fire("cancel"):
@@ -1463,14 +1483,15 @@ class PagedServeLoop:
             cur[i, 0] = self.slots[i]["cur"]
         tel = self.tel
         t0 = tel.now()
-        with tel.annotate("repro.serve.decode_step"):
+        with annotate("repro.serve.decode_step"):
             logits, self.caches = self._decode(
                 self.params, self.caches, jnp.asarray(cur),
                 jnp.asarray(self.lens), jnp.asarray(self.block_table),
             )
         self.decode_steps += 1
         self.slot_steps += len(live)
-        nxt = np.asarray(jnp.argmax(logits, -1))
+        with annotate("repro.serve.sync"):
+            nxt = np.asarray(jnp.argmax(logits, -1))
         # the argmax force above synchronised the device, so t1 covers
         # dispatch + execution; events go out BEFORE _accept so a
         # finishing slot's 'finished' mark follows its decode span
@@ -1513,7 +1534,7 @@ class PagedServeLoop:
                 self._ensure_writable(i, entry, blk)
         tel = self.tel
         t0 = tel.now()
-        with tel.annotate("repro.serve.verify_step"):
+        with annotate("repro.serve.verify_step"):
             logits, self.caches = self._verify(
                 self.params, self.caches, jnp.asarray(toks),
                 jnp.asarray(self.lens), jnp.asarray(n_writes),
@@ -1521,7 +1542,8 @@ class PagedServeLoop:
             )
         self.spec_steps += 1
         self.slot_steps += len(live)
-        greedy = np.asarray(jnp.argmax(logits, -1))          # [B, K1]
+        with annotate("repro.serve.sync"):
+            greedy = np.asarray(jnp.argmax(logits, -1))      # [B, K1]
         t1 = tel.now()
         tel.observe("phase.verify_s", t1 - t0)
         freed = False
@@ -1576,7 +1598,8 @@ class PagedServeLoop:
                     self.params, self.caches, jnp.asarray(cur),
                     jnp.asarray(pos), jnp.asarray(bt))
                 logits = logits[0]
-            return np.asarray(logits, np.float32)
+            with annotate("repro.serve.sync"):
+                return np.asarray(logits, np.float32)
         finally:
             self.pages.release(blocks)
 

@@ -224,12 +224,15 @@ class Scheduler:
         return max(cands,
                    key=lambda e: (self.effective_priority(e), -e.seq))
 
-    def pop(self, ent: SchedEntry) -> None:
-        """Remove an entry the loop is admitting; records its queue
-        wait (time since the latest enqueue — a resume's wait counts
-        from its requeue, not first submission; TTFT covers that)."""
+    def pop(self, ent: SchedEntry) -> float:
+        """Remove an entry the loop is admitting; records and returns
+        its queue wait (time since the latest enqueue — a resume's wait
+        counts from its requeue, not first submission; TTFT covers
+        that)."""
         self._q.remove(ent)
-        self.queue_wait_s.observe(time.monotonic() - ent.t_enqueue)
+        wait = time.monotonic() - ent.t_enqueue
+        self.queue_wait_s.observe(wait)
+        return wait
 
     def remove(self, ent: SchedEntry) -> None:
         """Drop a queued entry without admitting it (cancel / deadline

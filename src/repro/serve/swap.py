@@ -49,6 +49,7 @@ import jax
 import numpy as np
 
 from repro.serve.faults import NULL_FAULTS
+from repro.serve.telemetry import annotate
 
 __all__ = ["HostPage", "SwapStore", "StagingRing", "page_checksum"]
 
@@ -362,7 +363,8 @@ class StagingRing:
         meta, dev = item
         # np.asarray blocks until the dispatched gather lands on host;
         # per-page slicing downstream copies out of this buffer.
-        return meta, jax.tree.map(np.asarray, dev)
+        with annotate("repro.serve.sync"):
+            return meta, jax.tree.map(np.asarray, dev)
 
     def stage(self, meta, device_tree) -> List[tuple]:
         """Enqueue one transaction; return any that matured to host."""

@@ -27,11 +27,24 @@ This module supplies the shared vocabulary:
   ``chrome://tracing`` or https://ui.perfetto.dev: one named track per
   request plus a ``serve-loop`` track for step phases, so a full serve
   run is visually inspectable).
-- **Device/host alignment**: ``Telemetry.annotate`` wraps a host-side
-  region in ``jax.profiler.TraceAnnotation`` so a device profile
-  (``jax.profiler.trace``) lines up with the host spans; the compiled
-  forwards additionally carry ``jax.named_scope`` labels
-  (models/lm.py) inside the traced graph.
+- **Profiler spans**: ``annotate`` opens a ``jax.profiler.TraceAnnotation``
+  around a host region of the serve loop (``repro.serve.step``,
+  ``.admit``, ``.prefill_chunk``, ``.decode_step``, ``.verify_step``,
+  ``.cow_copy``, ``.swap_gather``, ``.swap_scatter``, and ``.sync``
+  around every host fetch of a device value).  They are emitted whether
+  or not telemetry is on: without a profiler session each costs one
+  check in C++ and the context manager's call.  Inside a
+  ``jax.profiler`` capture they sit on the profile's own clock beside
+  the device operations, which carry ``jax.named_scope`` labels
+  (``repro.lm.*`` in models/lm.py, ``repro.lookup_gemm`` in
+  models/nn.py).
+- **One clock for both**: the program stamps on ``time.monotonic``
+  (the tracer's epoch, the scheduler's ``t_submit``/``t_enqueue``,
+  deadlines).  ``repro.serve.step`` carries the loop's
+  ``time.monotonic()`` at its start as its ``monotonic_s`` argument,
+  so any capture maps a monotonic stamp ``t`` to profile time
+  ``step.start + (t - step.monotonic_s)``; the exports state the
+  tracer's epoch on that clock (``trace_epoch_monotonic_s``).
 
 Everything here is host-side Python around the jitted calls: enabling
 telemetry cannot change what the device computes (tracing on/off is
@@ -40,7 +53,8 @@ invariant ``check_compiled`` stays green).  When disabled
 (``cfg.serve_telemetry`` off) the loop holds the shared ``NULL``
 no-op facade: every hook is an attribute test or an empty method —
 measured overhead is gated ≤ 3% of decode wall time in CI *with
-telemetry on*; off is far below that.
+telemetry on*; off is far below that.  The profiler spans are not part
+of the facade: they are always on.
 """
 
 from __future__ import annotations
@@ -54,6 +68,7 @@ from contextlib import contextmanager
 from typing import Any, Dict, Iterable, List, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 # Bounded-memory defaults.  The reservoir cap bounds quantile memory;
 # below it the reservoir holds EVERY sample, so summaries agree exactly
@@ -81,6 +96,13 @@ def jsonable(obj):
     if isinstance(obj, (np.bool_,)):
         return bool(obj)
     return obj
+
+
+def annotate(name: str, **args) -> TraceAnnotation:
+    """A host span of the serve loop on the profiler's clock (always
+    on; see the module docstring).  ``args`` are recorded with the span
+    only while a profiler session is active."""
+    return TraceAnnotation(name, **args)
 
 
 # ---------------------------------------------------------------------------
@@ -363,6 +385,7 @@ class Tracer:
         the number of events written."""
         with open(path, "w") as f:
             f.write(json.dumps({"trace_epoch_unix_s": self.t_wall_epoch,
+                                "trace_epoch_monotonic_s": self.t0,
                                 "events": len(self.events),
                                 "dropped": self.dropped}) + "\n")
             for ev in self.events:
@@ -376,7 +399,8 @@ class Tracer:
         (``serve-loop``) for loop-phase spans; ``ts``/``dur`` in
         microseconds as the format requires.  Spans are complete
         events (ph 'X'); zero-duration lifecycle marks are instants
-        (ph 'i', thread-scoped)."""
+        (ph 'i', thread-scoped).  ``otherData`` states the epoch, on
+        ``time.monotonic`` and in UTC, that ``ts`` counts from."""
         trace: List[dict] = [{
             "name": "process_name", "ph": "M", "pid": 0, "tid": 0,
             "args": {"name": "repro.serve"},
@@ -404,7 +428,10 @@ class Tracer:
             trace.append(base)
         with open(path, "w") as f:
             json.dump({"traceEvents": trace,
-                       "displayTimeUnit": "ms"}, f)
+                       "displayTimeUnit": "ms",
+                       "otherData": {
+                           "trace_epoch_monotonic_s": self.t0,
+                           "trace_epoch_unix_s": self.t_wall_epoch}}, f)
         return len(self.events)
 
 
@@ -427,8 +454,8 @@ _NULL_CTX = _NullContext()
 
 
 class Telemetry:
-    """The enabled facade: registry + tracer + device-profile
-    annotation, bundled so instrumentation sites need one handle."""
+    """The enabled facade: registry + tracer, bundled so
+    instrumentation sites need one handle."""
 
     enabled = True
 
@@ -463,13 +490,6 @@ class Telemetry:
 
     def span(self, name: str, rid: Optional[int] = None, **attrs):
         return self.tracer.span(name, rid, **attrs)
-
-    def annotate(self, name: str):
-        """Host-side region annotation that shows up on the device
-        timeline when a ``jax.profiler`` session is active — this is
-        what lines a captured device profile up with the host spans."""
-        from jax.profiler import TraceAnnotation
-        return TraceAnnotation(name)
 
     def export(self, chrome_path: Optional[str] = None,
                jsonl_path: Optional[str] = None) -> Dict[str, Any]:
@@ -516,9 +536,6 @@ class _NullTelemetry:
         pass
 
     def span(self, name: str, rid: Optional[int] = None, **attrs):
-        return _NULL_CTX
-
-    def annotate(self, name: str):
         return _NULL_CTX
 
     def export(self, chrome_path: Optional[str] = None,
