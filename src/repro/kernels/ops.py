@@ -2,12 +2,15 @@
 
 ``impl`` selects the execution path:
 - 'ref'       : obvious jnp oracle (tests, tiny shapes)
-- 'xla'       : memory-bounded XLA formulation — scan over k-group
-                chunks, gather + one-hot MXU contraction.  This is the
-                path the production serve graph lowers (CPU dry-run +
-                TPU alike) and the one the roofline reads.
+- 'xla'       : memory-bounded XLA formulation — outer scan over
+                N-tiles, inner loop over k-group chunks, gather + one-hot
+                MXU contraction, dequant fused per tile.  The MoE serve
+                linears (experts vmapped) lower this path.
 - 'xla-kscan' : scan over k-chunks with a full [M, N] accumulator —
-                keeps n_tiles a sharded tensor dim for TP layers.
+                keeps n_tiles a sharded tensor dim for TP layers.  The
+                dense serve linears lower this path (the untuned
+                default of ``impl='auto'`` there), on one chip and under
+                a TP mesh.
 - 'xla-flat'  : no scan at all — one gather + one one-hot GEMM per bit
                 plane.  Fastest when the [kg*2^G, N] expanded table fits
                 comfortably (small K or small N), pays full
@@ -79,6 +82,18 @@ def pack_bitplanes(
 _rowbase = rowbase_from_plan
 
 
+def _kscan_chunk(kg: int, chunk: int) -> int:
+    """The k-chunk ``tlmac_matmul_xla_kscan`` scans by: ``chunk`` capped
+    at kg where it divides kg, else the largest divisor of kg in
+    [64, chunk], so no k-group is padded (kg 576 -> 192, 1440 -> 240).
+    Where kg has no such divisor, ``chunk`` itself, and the caller pads."""
+    chunk = min(chunk, kg)
+    for d in range(chunk, 63, -1):
+        if kg % d == 0:
+            return d
+    return chunk
+
+
 @functools.partial(jax.jit, static_argnames=("B_a", "G", "N", "chunk"))
 def tlmac_matmul_xla_kscan(
     a_codes: jnp.ndarray,
@@ -99,7 +114,22 @@ def tlmac_matmul_xla_kscan(
     N-tile-scan variant pays an all-to-all there).  The f32 [M, N]
     buffer is acceptable per matmul at dense sizes; the expert-stacked
     case (E buffers at once under vmap) uses the N-tile variant.
+
+    The weight-side expansion crosses HBM once per k-chunk, in the
+    dtype and layout the dot reads: the table is cast to bf16 before
+    the gather (exact: |table| <= G*2^(B_w-1) <= 48), and its rows are
+    gathered as columns of the transposed table, ``[C, n_tiles, chunk,
+    D_p]``, so the gathered operand is laid out lane-dense in the chunk
+    and n_tiles stays a tensor dim.  The bit planes share it through one
+    dot whose lhs folds them, ``sum_b 2^b one_hot(code_b)``: integers
+    below 2^B_a, exact in bf16 for B_a <= 8, accumulated in f32, so the
+    integer result equals the per-plane sum.  The scan steps by
+    ``_kscan_chunk(kg, chunk)``; k-groups are padded (with a zero table
+    row) only where kg has no divisor in [64, chunk].
     """
+    if B_a > 8:
+        raise ValueError(f"B_a={B_a}: the folded bit-plane lhs is exact "
+                         "in bf16 only for B_a <= 8")
     M, K = a_codes.shape
     D_s, D_p = exec_idx.shape
     n_tiles = N // D_p
@@ -108,10 +138,10 @@ def tlmac_matmul_xla_kscan(
 
     if codes is None:
         codes = _ref.pack_bitplanes_ref(a_codes, B_a, G)
-    t2d = table.reshape(-1, C)
+    t2d = table.reshape(-1, C).astype(jnp.bfloat16)
     rowbase = _rowbase(table, exec_idx, step_cluster, n_tiles, kg)
 
-    chunk = min(chunk, kg)
+    chunk = _kscan_chunk(kg, chunk)
     pad_k = (-kg) % chunk
     R = t2d.shape[0]
     if pad_k:
@@ -126,19 +156,19 @@ def tlmac_matmul_xla_kscan(
     rb_s = jnp.moveaxis(
         rowbase.reshape(n_tiles, nchunks, chunk, D_p), 1, 0
     )
+    tT = t2d.T                                               # [C, R]
 
     def body(acc, xs):
-        cb, rb = xs
-        t_rows = t2d[rb].astype(jnp.bfloat16)
-        rhs = t_rows.transpose(0, 2, 1, 3).reshape(n_tiles * D_p, chunk * C)
-        for b in range(B_a):
-            sel = jax.nn.one_hot(cb[b], C, dtype=jnp.bfloat16)
-            acc = acc + float(1 << b) * jax.lax.dot_general(
-                sel.reshape(M, chunk * C), rhs,
-                (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ).reshape(M, n_tiles, D_p)
-        return acc, None
+        cb, rb = xs                          # [B_a, M, chunk], [nt, chunk, D_p]
+        sel = sum(
+            float(1 << b) * jax.nn.one_hot(cb[b], C, dtype=jnp.bfloat16)
+            for b in range(B_a)
+        )                                                    # [M, chunk, C]
+        rhs = tT[:, rb]                                      # [C, nt, chunk, D_p]
+        return acc + jax.lax.dot_general(
+            sel, rhs, (((1, 2), (2, 0)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ), None                                              # [M, nt, D_p]
 
     acc0 = jnp.zeros((M, n_tiles, D_p), dtype=jnp.float32)
     acc, _ = jax.lax.scan(body, acc0, (codes_s, rb_s))

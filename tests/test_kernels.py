@@ -34,6 +34,12 @@ SWEEP = [
     (32, 128, 16, 3, 4, 4),
     (48, 64, 5, 4, 4, 6),
     (64, 192, 33, 2, 3, 4),
+    # k-chunk edges of 'xla-kscan' at G 4: kg 576 and 1440 (the serve
+    # widths 2304 and 5760) scan by the divisors 192 and 240 unpadded;
+    # kg 257 has no divisor in [64, 256] and takes the padded k-groups
+    (2304, 128, 5, 3, 3, 4),
+    (5760, 128, 3, 3, 3, 4),
+    (1028, 128, 4, 3, 3, 4),
 ]
 
 
@@ -47,6 +53,15 @@ def test_tlmac_matmul_all_impls_bitexact(K, N, M, B_w, B_a, G):
             ops.tlmac_matmul(a, t, e, c, B_a=B_a, G=G, N=N, impl=impl)
         )
         assert np.array_equal(out, ref), impl
+
+
+# kg -> the k-chunk 'xla-kscan' scans by at the default chunk 256: the
+# serve widths of minicpm-2b (576, 1440) and codeqwen1.5-7b (1024, 3360),
+# kg below the chunk, and kg 257 (prime: padded by chunks of 256)
+@pytest.mark.parametrize("kg,want", [(576, 192), (1440, 240), (1024, 256),
+                                     (3360, 240), (40, 40), (257, 256)])
+def test_kscan_chunk_divides_kg_where_it_can(kg, want):
+    assert ops._kscan_chunk(kg, 256) == want
 
 
 @given(

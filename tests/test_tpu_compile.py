@@ -148,3 +148,31 @@ def test_pallas_lookup_kernels_are_refused_for_v5e(one_chip, impl):
         act = _sds(one_chip, (B_a, M, kg), i32)
     with pytest.raises(ValueError, match="block shape"):
         jax.jit(fn).lower(act, rowbase, table).compile()
+
+
+def test_kscan_lookup_gemm_expands_in_bf16_unpadded_for_v5e(one_chip):
+    """The dense serve linears' lookup GEMM at minicpm-2b's 2304 x 2304
+    (dp 144) and 32 decode rows: the table expansion is gathered in bf16,
+    never as an int32 tensor that a convert pass reads again; kg 576
+    scans by its divisor 192 with no padded k-group; and the compiler's
+    byte count stays under 1.5 GB (the int32, padded expansion of 256-
+    group chunks counted 3.56 GB)."""
+    from repro.kernels.ops import tlmac_matmul_xla_kscan
+
+    M, K, N, dp, G = 32, 2304, 2304, 144, 4
+    kg, n_tiles = K // G, N // dp
+    i32 = jnp.int32
+    lowered = tlmac_matmul_xla_kscan.lower(
+        _sds(one_chip, (M, K), i32), _sds(one_chip, (4, 4096, 2**G), i32),
+        _sds(one_chip, (n_tiles * kg, dp), i32),
+        _sds(one_chip, (n_tiles * kg,), i32), B_a=3, G=G, N=N)
+    text = lowered.as_text()
+    assert "pad" not in text
+    # the scanned rowbase: 576 / 192 = 3 chunks of [16, 192, 144]
+    assert "tensor<3x16x192x144xi32>" in text
+    compiled = lowered.compile()
+    gathers = [ln for ln in compiled.as_text().splitlines()
+               if " gather(" in ln]
+    assert gathers
+    assert not [ln for ln in gathers if "= s32[" in ln], gathers
+    assert compiled.cost_analysis()["bytes accessed"] <= 1.5e9
